@@ -265,8 +265,15 @@ _SHARDED_CHILD = textwrap.dedent(
 )
 
 
+# the mesh children run on forced CPU host devices, never on the chip:
+# every row they produce says so
+_CPU_REHEARSAL = "platform=cpu_forced_devices"
+
+
 def _run_sharded_child(devices: int, smoke: bool) -> dict:
     env = dict(os.environ)
+    # the parent may hold the accelerator; a chip serves one process
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}"
     )
@@ -313,17 +320,17 @@ def bench_sharded(rows: list, *, smoke: bool = False,
              f"rebal_rounds={r['rebalance_rounds']};"
              f"rebal_moved={r['rebalance_rows_moved']};"
              f"rebal_us={r['rebalance_seconds'] * 1e6:.0f};"
-             f"{level_detail}"),
+             f"{level_detail};{_CPU_REHEARSAL}"),
         ))
         rows.append((
             f"enum/sharded_parity_D={d}", 0.0,
-            "ok" if r["parity"] else "MISMATCH",
+            ("ok" if r["parity"] else "MISMATCH") + f";{_CPU_REHEARSAL}",
         ))
     d_max_count = max(device_counts)
     rows.append((
         "enum/sharded_speedup", 0.0,
         f"D={d_max_count}_vs_D=1="
-        f"{times[1] / times[d_max_count]:.2f}x",
+        f"{times[1] / times[d_max_count]:.2f}x;{_CPU_REHEARSAL}",
     ))
 
 

@@ -74,6 +74,9 @@ def main() -> None:
                          "span tree as Chrome/Perfetto trace JSON")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     tracer_cm = contextlib.nullcontext(None)
     if args.trace:
         from repro import obsv

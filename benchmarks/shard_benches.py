@@ -101,8 +101,15 @@ _CHILD = textwrap.dedent(
 )
 
 
+# the children run on forced CPU host devices, never on the chip: every
+# row they produce says so
+_CPU_REHEARSAL = "platform=cpu_forced_devices"
+
+
 def _run_child(devices: int, smoke: bool) -> dict:
     env = dict(os.environ)
+    # the parent may hold the accelerator; a chip serves one process
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices}"
     )
@@ -126,14 +133,14 @@ def run_all(*, smoke: bool = False, device_counts=(1, 2, 4)) -> list:
         r = _run_child(d, smoke)
         rows.append((
             f"shard/ilgf_D={d}", r["t_ilgf"] * 1e6,
-            f"V={r['n_v']};E={r['n_e']};iters={r['iters']}",
+            f"V={r['n_v']};E={r['n_e']};iters={r['iters']};{_CPU_REHEARSAL}",
         ))
         rows.append((
             f"shard/round_D={d}", r["t_round"] * 1e6,
-            f"B={r['batch']}",
+            f"B={r['batch']};{_CPU_REHEARSAL}",
         ))
         rows.append((
             f"shard/parity_D={d}", 0.0,
-            "ok" if r["parity"] else "MISMATCH",
+            ("ok" if r["parity"] else "MISMATCH") + f";{_CPU_REHEARSAL}",
         ))
     return rows

@@ -46,34 +46,25 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.5: public API with the ``check_vma`` kwarg
-    _shard_map = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:  # jax 0.4.x: experimental API, kwarg named ``check_rep``
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map_nocheck(*, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` decorator with replication checks off."""
-    return functools.partial(
-        _shard_map,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_SHARD_MAP_CHECK_KW: False},
-    )
-
 
 from repro.core import filters as flt
 from repro.core.cni import default_max_p
 from repro.core.ilgf import IlgfResult, prepare_query
 from repro.core.labels import build_label_map, ord_of
 from repro.graphs.csr import Graph, max_degree
+
+
+def shard_map_nocheck(*, mesh, in_specs, out_specs):
+    """``jax.shard_map`` decorator with replication (vma) checks off."""
+    return functools.partial(
+        jax.shard_map,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -151,7 +142,8 @@ def shard_edges(src, dst, plan: PartitionPlan) -> ShardedEdges:
     (u→w) direction lands on owner(u) and (w→u) on owner(w) — the host-side
     materialization of the owner/ghost boundary exchange: a cross-shard edge
     is present in both endpoint owners' buckets, each in the direction that
-    feeds its *owned* count row.
+    feeds its *owned* count row.  Host arrays; ``prepare_sharded_edges``
+    places bucket i on the device that owns shard i.
     """
     src = np.asarray(src, dtype=np.int32)
     dst = np.asarray(dst, dtype=np.int32)
@@ -165,7 +157,7 @@ def shard_edges(src, dst, plan: PartitionPlan) -> ShardedEdges:
         es[i, : b.size] = src[b]
         ed[i, : b.size] = dst[b]
         ok[i, : b.size] = True
-    return ShardedEdges(jnp.asarray(es), jnp.asarray(ed), jnp.asarray(ok))
+    return ShardedEdges(es, ed, ok)
 
 
 def prepare_sharded_edges(data, mesh: Mesh, axis: str = "data"):
@@ -213,9 +205,12 @@ def prepare_sharded_edges(data, mesh: Mesh, axis: str = "data"):
             es[i, :k] = srcs[i]
             ed[i, :k] = dsts[i]
             ok[i, :k] = True
-        se = ShardedEdges(jnp.asarray(es), jnp.asarray(ed), jnp.asarray(ok))
-        return se, plan, g
-    return shard_edges(np.asarray(g.src), np.asarray(g.dst), plan), plan, g
+        se = ShardedEdges(es, ed, ok)
+    else:
+        se = shard_edges(np.asarray(g.src), np.asarray(g.dst), plan)
+    # bucket i lives on the device that owns shard i, not on device 0
+    rows = NamedSharding(mesh, P(axis))
+    return ShardedEdges(*(jax.device_put(x, rows) for x in se)), plan, g
 
 
 # ---------------------------------------------------------------------------

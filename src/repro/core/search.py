@@ -637,7 +637,9 @@ def device_join_search(
     n_q = query.vlabels.shape[0]
     n_d = data.vlabels.shape[0]
     q_adj = _host_adjacency(query)
-    elab_np = _dense_edge_labels(data, n_d)
+    # lane-aligned vertex axis: filtered graphs of nearby sizes share one
+    # compiled program per level shape (ids >= n_d are never addressed)
+    elab_np = _dense_edge_labels(data, _align_rows(n_d))
     elab_dev = None
 
     if order is None:
@@ -883,7 +885,9 @@ def sharded_device_join_search(
     n_q = query.vlabels.shape[0]
     n_d = data.vlabels.shape[0]
     q_adj = _host_adjacency(query)
-    elab_np = _dense_edge_labels(data, n_d)
+    # lane-aligned vertex axis: filtered graphs of nearby sizes share one
+    # compiled program per level shape (ids >= n_d are never addressed)
+    elab_np = _dense_edge_labels(data, _align_rows(n_d))
     elab_dev = None
 
     if order is None:

@@ -54,4 +54,4 @@ def cni_update(
         block_f=block_f,
         interpret=not _on_tpu(),
     )
-    return new_rows[:f], log_out[:f], deg_out[:f]
+    return new_rows[:f], log_out[:f, 0], deg_out[:f, 0]

@@ -42,20 +42,28 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _block_n(n: int) -> int:
+    """Contraction chunk of the one-hot gather: the lane-aligned N, capped
+    at 1,024 rows so the (block_r, block_n) one-hot stays small at the
+    vertex cap."""
+    return min(1024, max(128, -(-n // 128) * 128))
+
+
 def _padded_kernel_args(table, row_valid, cand_list, cand_valid, elab_cols,
                         q_pos, q_lab, q_valid, block_r, block_c):
-    """Tile-align every operand the Pallas kernels consume."""
+    """Tile-align every operand the Pallas kernels consume, in the 2-D
+    layouts they read: row vectors (R, 1), candidate vectors (1, C)."""
     r = table.shape[0]
     c = cand_list.shape[0]
     n = elab_cols.shape[0]
     pad_r = (-r) % block_r
     pad_c = (-c) % block_c
-    pad_n = (-n) % 128  # lane-align the contraction axis for the MXU
+    pad_n = (-n) % _block_n(n)
     return (
         jnp.pad(table, ((0, pad_r), (0, 0))),
-        jnp.pad(jnp.asarray(row_valid, jnp.int32), (0, pad_r)),
-        jnp.pad(cand_list, (0, pad_c)),
-        jnp.pad(jnp.asarray(cand_valid, jnp.int32), (0, pad_c)),
+        jnp.pad(jnp.asarray(row_valid, jnp.int32), (0, pad_r))[:, None],
+        jnp.pad(cand_list, (0, pad_c))[None, :],
+        jnp.pad(jnp.asarray(cand_valid, jnp.int32), (0, pad_c))[None, :],
         jnp.pad(
             jnp.asarray(elab_cols, jnp.float32),
             ((0, pad_n), (0, pad_c)),
@@ -98,6 +106,7 @@ def embed_join_raw(
                              block_r, block_c),
         block_r=block_r,
         block_c=block_c,
+        block_n=_block_n(elab_cols.shape[0]),
         interpret=not _on_tpu(),
     )
     return mask[:r, :c].astype(bool)
@@ -141,6 +150,7 @@ def embed_join_count_raw(
                              block_r, block_c),
         block_r=block_r,
         block_c=block_c,
+        block_n=_block_n(elab_cols.shape[0]),
         interpret=not _on_tpu(),
     )
     return counts[:r, 0]

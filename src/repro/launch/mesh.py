@@ -18,7 +18,9 @@ FSDP_PARAM_THRESHOLD = 8e9  # shard weights over data axis above this
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_policy(cfg: ModelConfig, mesh: Mesh, *, rules=None) -> ShardingPolicy:

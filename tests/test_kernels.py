@@ -67,6 +67,9 @@ class TestEmbedJoinKernel:
         (64, 3, 32, 50, 2, 32, 16),
         (100, 1, 33, 40, 1, 64, 32),   # non-multiples — wrapper pads
         (16, 5, 128, 130, 4, 256, 64),  # blocks larger than R; N > 128
+        # served widths: 16-vertex query (T = 15), J = 4, N > one
+        # 1,024-row contraction chunk
+        (300, 15, 200, 1100, 4, 256, 128),
     ])
     def test_matches_ref(self, r, t, c, n, j, br, bc):
         args = self._random_inputs(r, t, c, n, j, seed=r + c)
@@ -79,6 +82,9 @@ class TestEmbedJoinKernel:
         (64, 3, 32, 50, 2, 32, 16),
         (100, 1, 33, 40, 1, 64, 32),   # non-multiples — wrapper pads
         (16, 5, 128, 130, 4, 256, 64),  # blocks larger than R; N > 128
+        # served widths: 16-vertex query (T = 15), J = 4, N > one
+        # 1,024-row contraction chunk
+        (300, 15, 200, 1100, 4, 256, 128),
     ])
     def test_count_matches_ref(self, r, t, c, n, j, br, bc):
         """Count pass: the in-core row-sum kernel == oracle == grid sum."""
@@ -131,6 +137,42 @@ class TestEmbedJoinKernel:
         inj = (args[0][:, :, None] != args[2][None, None, :]).all(axis=1)
         exp = inj & args[1][:, None] & args[3][None, :]
         np.testing.assert_array_equal(got, exp)
+
+
+class TestCniUpdateKernel:
+    def test_matches_ref_at_human_widths(self):
+        """HUMAN's 44 labels at its index ``d_max`` (64) and ``max_p``;
+        the small-width case lives in test_incremental.py."""
+        from repro.kernels.cni_update.ops import cni_update
+        from repro.kernels.cni_update.ref import cni_update_ref
+
+        f, L, d_max = 300, 44, 64
+        rng = np.random.default_rng(f + L)
+        mp = default_max_p(d_max, L)
+        # sparse rows, one empty row and one at exactly d_max; deltas
+        # that would push a row past d_max are dropped
+        rows = (rng.integers(0, 3, size=(f, L))
+                * (rng.random((f, L)) < 4.0 / L)).astype(np.int32)
+        rows[:2] = 0
+        np.add.at(rows[1], rng.integers(0, L, size=d_max), 1)
+        delta = np.maximum(
+            rng.integers(-1, 2, size=(f, L)).astype(np.int32), -rows
+        )
+        delta[:2] = 0
+        delta[(rows + delta).sum(axis=1) > d_max] = 0
+        nr_k, log_k, deg_k = cni_update(
+            jnp.asarray(rows), jnp.asarray(delta), d_max=d_max, max_p=mp,
+        )
+        nr_r, log_r, deg_r = cni_update_ref(
+            jnp.asarray(rows), jnp.asarray(delta), d_max, mp
+        )
+        assert int(np.asarray(deg_r)[1]) == d_max
+        np.testing.assert_array_equal(np.asarray(nr_k), np.asarray(nr_r))
+        np.testing.assert_array_equal(np.asarray(deg_k), np.asarray(deg_r))
+        lk, lr = np.asarray(log_k), np.asarray(log_r)
+        fin = np.isfinite(lr)
+        assert (np.isfinite(lk) == fin).all()
+        np.testing.assert_allclose(lk[fin], lr[fin], rtol=1e-5, atol=1e-5)
 
 
 class TestCandidateFilterKernel:
