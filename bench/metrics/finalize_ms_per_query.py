@@ -1,0 +1,9 @@
+"""Mean duration of a query's finalize (refine, plan, enumerate):
+``service.finalize`` spans."""
+
+from readers import mean_ms, spans_in_window
+
+
+def read(ctx):
+    return mean_ms([s.duration_ns for s in
+                    spans_in_window(ctx, "service.finalize")])
