@@ -421,7 +421,21 @@ def empty_enum_report() -> dict:
       (levels repartitioned, parent rows exchanged, wall-clock cost);
     * ``levels``        — per-level records ``{"level", "emit_rows":
       [per-shard rows], "rebalanced", "rebalance_seconds"}`` backing the
-      bench JSON's per-level rebalance timings.
+      bench JSON's per-level rebalance timings;
+    * ``host_syncs``    — points where the join's host code blocked on a
+      device value, counted where each one ran:
+
+      - the per-phase ``block_until_ready`` after count (single-device
+        kernel path) and after emit (every path), taken only with
+        ``report`` — the phase timings need them;
+      - the survivor total of a level: the scalar ``int(inclusive[-1])``
+        (single-device kernel path) or the (D,) per-shard totals
+        ``np.asarray(totals_j)`` (sharded kernel path);
+      - the (D, pcap) counts the sharded rebalancer pulls to recut;
+      - each validity bitmask of the host-assisted scan:
+        ``np.asarray(valid)`` per row slice, ``np.asarray(valid_j)`` per
+        level when sharded;
+      - the final table readback (not taken when a level leaves no row).
     """
     # generated from the typed schema of record (obsv.reports.EnumReport)
     # so the searcher-side plain dict and the stats.extras dataclass can
@@ -712,6 +726,7 @@ def device_join_search(
             counts = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
             if report is not None:
                 counts.block_until_ready()
+                stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["count_seconds"] += t1 - t0
             obsv.span_at("enum.count", t0, t1, level=t, rows=n_rows)
@@ -721,6 +736,7 @@ def device_join_search(
             inclusive = jnp.cumsum(counts)
             row_off = inclusive - counts
             total = int(inclusive[-1])
+            stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["scan_seconds"] += t1 - t0
             obsv.span_at("enum.scan", t0, t1, level=t)
@@ -749,6 +765,7 @@ def device_join_search(
             )
             if report is not None:
                 table_dev.block_until_ready()
+                stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["emit_seconds"] += t1 - t0
             obsv.span_at("enum.emit", t0, t1, level=t, rows=total)
@@ -767,6 +784,7 @@ def device_join_search(
                     n_cand_dev, elab_dev, qp, ql, qv, use_kernel=False,
                 )
                 ri, ci = np.nonzero(np.asarray(valid))
+                stats["host_syncs"] += 1
                 if ri.size:
                     r_list.append(ri.astype(np.int32) + np.int32(lo))
                     c_list.append(ci.astype(np.int32))
@@ -802,6 +820,7 @@ def device_join_search(
             )
             if report is not None:
                 table_dev.block_until_ready()
+                stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["emit_seconds"] += t1 - t0
             obsv.span_at("enum.emit", t0, t1, level=t, rows=total)
@@ -818,6 +837,7 @@ def device_join_search(
     if max_embeddings is not None:
         n_keep = min(n_keep, max_embeddings)
     table = np.asarray(table_dev[:n_keep])
+    stats["host_syncs"] += 1
     if report is not None:
         report.update(stats)
     return _restore_query_order(table, order)
@@ -948,6 +968,7 @@ def sharded_device_join_search(
                 qp, ql, qv,
             )
             shard_tot = np.asarray(totals_j).astype(np.int64)
+            stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["count_seconds"] += t1 - t0
             obsv.span_at("enum.count", t0, t1, level=t, rows=total,
@@ -971,6 +992,7 @@ def sharded_device_join_search(
                     > rebalance_threshold * new_total):
                 t_r = time.perf_counter()
                 counts_h = np.asarray(counts_j)  # (D, pcap) — pulled only now
+                stats["host_syncs"] += 1
                 weights = np.concatenate(
                     [counts_h[i, : sizes[i]] for i in range(n_shards)]
                 )
@@ -1025,6 +1047,7 @@ def sharded_device_join_search(
             )
             if report is not None:
                 table_j.block_until_ready()
+                stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["emit_seconds"] += t1 - t0
             obsv.span_at("enum.emit", t0, t1, level=t, rows=new_total)
@@ -1039,6 +1062,7 @@ def sharded_device_join_search(
                 qp, ql, qv,
             )
             valid_h = np.asarray(valid_j)  # (D, pcap, c_pad) bool
+            stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["count_seconds"] += t1 - t0
             obsv.span_at("enum.count", t0, t1, level=t, rows=total,
@@ -1123,6 +1147,7 @@ def sharded_device_join_search(
             )
             if report is not None:
                 table_j.block_until_ready()
+                stats["host_syncs"] += 1
             t1 = time.perf_counter()
             stats["emit_seconds"] += t1 - t0
             obsv.span_at("enum.emit", t0, t1, level=t, rows=new_total)
@@ -1153,6 +1178,7 @@ def sharded_device_join_search(
         flat = np.zeros((0, n_q), np.int32)
     else:
         table_out = np.asarray(table_j)
+        stats["host_syncs"] += 1
         flat = np.concatenate(
             [table_out[i, : sizes[i]] for i in range(n_shards)], axis=0
         )[:n_keep]

@@ -127,4 +127,5 @@ def cni_update_pallas(
             vmem_limit_bytes=max(32 * _MIB, work + 8 * _MIB),
         ),
         interpret=interpret,
+        name="cni_update",
     )(rows, delta, log_table)

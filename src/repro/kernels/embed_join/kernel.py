@@ -23,6 +23,11 @@ Two entry points share the validity math (``_validity_tile``):
   across candidate tiles), so only (R, 1) int32 leaves the core — no
   (R, C) materialization, no table writes.
 
+Each Pallas call carries a fixed ``name`` — ``embed_join_emit`` for the
+grid (on the kernel path it runs only inside the emit pass) and
+``embed_join_count`` — so a device trace names the kernels, whatever
+jitted wrapper calls them.
+
 Edge labels ride through the matmul as f32 at full (``HIGHEST``) precision,
 exact for labels < 2²⁴.  The neighbor count J and table width T are static,
 so both loops unroll.  Mosaic layout rules shape the operands: row vectors
@@ -149,7 +154,7 @@ def _embed_join_count_kernel(
     )
 
 
-def _call(kernel, out_spec, out_shape, table, row_valid, cand_list,
+def _call(kernel, name, out_spec, out_shape, table, row_valid, cand_list,
           cand_valid, elab_cols, q_pos, q_lab, q_valid, *, block_r, block_c,
           block_n, interpret):
     """Shared pallas_call plumbing of both kernels (same operands, grid)."""
@@ -179,6 +184,7 @@ def _call(kernel, out_spec, out_shape, table, row_valid, cand_list,
             vmem_limit_bytes=max(32 * _MIB, work + 8 * _MIB),
         ),
         interpret=interpret,
+        name=name,
     )(table, row_valid, cand_list, cand_valid, elab_cols, q_pos, q_lab,
       q_valid)
 
@@ -206,6 +212,7 @@ def embed_join_pallas(
     c = cand_list.shape[1]
     return _call(
         _embed_join_kernel,
+        "embed_join_emit",
         pl.BlockSpec((block_r, block_c), lambda i, k: (i, k)),
         jax.ShapeDtypeStruct((r, c), jnp.int8),
         table, row_valid, cand_list, cand_valid, elab_cols,
@@ -238,6 +245,7 @@ def embed_join_count_pallas(
     r = table.shape[0]
     return _call(
         _embed_join_count_kernel,
+        "embed_join_count",
         pl.BlockSpec((block_r, 1), lambda i, k: (i, 0)),
         jax.ShapeDtypeStruct((r, 1), jnp.int32),
         table, row_valid, cand_list, cand_valid, elab_cols,

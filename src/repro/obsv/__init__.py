@@ -4,8 +4,9 @@ Three pieces, one import surface (``from repro import obsv``):
 
 * **Tracing** (``obsv.trace``): per-query span trees on the monotonic
   clock, Chrome/Perfetto-exportable, zero-cost when no tracer is
-  installed.  Instrumented layers call ``obsv.span("enum.count", ...)``;
-  callers opt in with ``with obsv.tracing() as tracer: ...``.
+  installed; live spans also appear in a ``jax.profiler`` trace.
+  Instrumented layers call ``obsv.span("enum.count", ...)``; callers opt
+  in with ``with obsv.tracing() as tracer: ...``.
 * **Metrics** (``obsv.metrics``): counters / gauges / exponential-bucket
   histograms in a ``MetricsRegistry``, rendered in Prometheus exposition
   format and validated by the in-repo ``parse_prometheus`` checker.
@@ -43,6 +44,7 @@ from repro.obsv.trace import (
     enabled,
     end,
     get_tracer,
+    mirror,
     set_tracer,
     span,
     span_at,
@@ -70,6 +72,7 @@ __all__ = [
     "enabled",
     "end",
     "get_tracer",
+    "mirror",
     "parse_prometheus",
     "set_tracer",
     "span",

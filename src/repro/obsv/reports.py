@@ -235,6 +235,7 @@ class EnumReport(Report):
     rebalance_rounds: int
     rebalance_rows_moved: int
     rebalance_seconds: float
+    host_syncs: int
     levels: list = field(default_factory=list)
 
     def _check_levels(self, v):
@@ -264,7 +265,7 @@ class EnumReport(Report):
             scan_path=None, enum_shards=0,
             emit_rows_max=0, emit_rows_min=0,
             rebalance_rounds=0, rebalance_rows_moved=0,
-            rebalance_seconds=0.0, levels=[],
+            rebalance_seconds=0.0, host_syncs=0, levels=[],
         )
 
     def _check_scan_path(self, v):

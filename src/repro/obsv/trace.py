@@ -23,7 +23,15 @@ no branch beyond one global check.  Install a tracer for a scope with::
 
 All timestamps come from ``time.perf_counter_ns()`` (monotonic);
 ``span_at`` backfills *retroactive* spans (e.g. queue wait measured from a
-``time.perf_counter()`` submission stamp — same clock, float seconds).
+``time.perf_counter()`` submission stamp — same clock, float seconds), and
+``mirror`` records a finished span again under another parent with its
+exact timestamps.
+
+**Live spans reach the profiler.**  Each span opened with ``span(...)``
+also enters a ``jax.profiler.TraceAnnotation`` of its bare name for its
+lifetime, so a ``jax.profiler`` trace shows it on the host plane beside
+the device ops, on the profiler's clock.  Retroactive, mirrored and
+detached spans do not nest on the thread and stay out of the profiler.
 """
 
 from __future__ import annotations
@@ -135,11 +143,17 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        s = self.start_span(name, **attrs)
-        try:
-            yield s
-        finally:
-            self.end_span(s)
+        # imported here, so that obsv imports no JAX while tracing is off
+        from jax.profiler import TraceAnnotation
+
+        # the bare name only: annotation keywords are encoded into the
+        # profiler event's name
+        with TraceAnnotation(name):
+            s = self.start_span(name, **attrs)
+            try:
+                yield s
+            finally:
+                self.end_span(s)
 
     def span_at(self, name: str, start_s: float, end_s: float, *,
                 parent: Span | None = None, **attrs) -> Span:
@@ -149,6 +163,15 @@ class Tracer:
         s = self.start_span(name, parent=parent, detached=True, **attrs)
         s.start_ns = int(start_s * 1e9)
         s.end_ns = int(end_s * 1e9)
+        self.spans.append(s)
+        return s
+
+    def mirror(self, src: Span, *, parent: Span | None = None,
+               **attrs) -> Span:
+        """Record the closed span ``src`` again under ``parent``, with the
+        same name and exactly its ``start_ns``/``end_ns``."""
+        s = self.start_span(src.name, parent=parent, detached=True, **attrs)
+        s.start_ns, s.end_ns = src.start_ns, src.end_ns
         self.spans.append(s)
         return s
 
@@ -254,6 +277,13 @@ def span_at(name: str, start_s: float, end_s: float, *,
     if _ACTIVE is None:
         return None
     return _ACTIVE.span_at(name, start_s, end_s, parent=parent, **attrs)
+
+
+def mirror(src, *, parent: Span | None = None, **attrs) -> Span | None:
+    """Copy a closed live span under ``parent`` (no-op when disabled)."""
+    if _ACTIVE is None or not isinstance(src, Span):
+        return None
+    return _ACTIVE.mirror(src, parent=parent, **attrs)
 
 
 def start_detached(name: str, **attrs) -> Span | None:

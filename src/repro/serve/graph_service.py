@@ -677,45 +677,48 @@ class GraphQueryService:
             for r in group:
                 mask_np[r.slot] = True
             mask = jnp.asarray(mask_np)
-            # slots outside this epoch group are made inert for the dispatch
-            # (zero ords ⇒ empty alive ⇒ no work), so one trace serves all
-            qb = BatchedQueries(
-                ords=jnp.where(mask[:, None], self._ords, 0),
-                counts=self._counts, digest=self._digest, mnd=self._mnd,
-            )
             entry = self._epochs[epoch]
-            t_round = time.perf_counter()
-            if entry.sharded is not None:
-                from repro.core.distributed import sharded_batched_ilgf_round
+            with obsv.span("service.filter_round", epoch=epoch,
+                           group=len(group)) as round_span:
+                # slots outside this epoch group are made inert for the
+                # dispatch (zero ords ⇒ empty alive ⇒ no work), so one
+                # trace serves all
+                qb = BatchedQueries(
+                    ords=jnp.where(mask[:, None], self._ords, 0),
+                    counts=self._counts, digest=self._digest, mnd=self._mnd,
+                )
+                if entry.sharded is not None:
+                    from repro.core.distributed import (
+                        sharded_batched_ilgf_round,
+                    )
 
-                se, plan = entry.sharded
-                new_alive, cand, changed = sharded_batched_ilgf_round(
-                    se, plan, qb, self._alive & mask[:, None],
-                    mesh=self.cfg.mesh, axis=self.cfg.shard_axis,
-                    n_labels=self.cfg.max_query_labels,
-                    d_max=self.d_max, max_p=self.max_p,
-                    variant=self.cfg.filter_variant,
-                )
-            else:
-                new_alive, cand, changed = batched_ilgf_round(
-                    entry.snapshot.graph, qb,
-                    self._alive & mask[:, None],
-                    n_labels=self.cfg.max_query_labels,
-                    d_max=self.d_max, max_p=self.max_p,
-                    variant=self.cfg.filter_variant,
-                )
-            converged = ~np.asarray(changed)
+                    se, plan = entry.sharded
+                    new_alive, cand, changed = sharded_batched_ilgf_round(
+                        se, plan, qb, self._alive & mask[:, None],
+                        mesh=self.cfg.mesh, axis=self.cfg.shard_axis,
+                        n_labels=self.cfg.max_query_labels,
+                        d_max=self.d_max, max_p=self.max_p,
+                        variant=self.cfg.filter_variant,
+                    )
+                else:
+                    new_alive, cand, changed = batched_ilgf_round(
+                        entry.snapshot.graph, qb,
+                        self._alive & mask[:, None],
+                        n_labels=self.cfg.max_query_labels,
+                        d_max=self.d_max, max_p=self.max_p,
+                        variant=self.cfg.filter_variant,
+                    )
+                converged = ~np.asarray(changed)
             alive_merged = jnp.where(mask[:, None], new_alive, alive_merged)
             self._m_rounds.inc()
-            t_round_end = time.perf_counter()
             for req in group:
                 req.rounds += 1
                 # one fused dispatch serves the whole epoch group; the
-                # shared round is mirrored into each member's request trace
-                # (flagged ``shared`` so durations aren't summed naively)
-                obsv.span_at("service.filter_round", t_round, t_round_end,
-                             parent=req.span, round=req.rounds,
-                             epoch=epoch, shared=len(group) > 1)
+                # shared round is mirrored, with the live span's exact
+                # interval, into each member's request trace (flagged
+                # ``shared`` so durations aren't summed naively)
+                obsv.mirror(round_span, parent=req.span, round=req.rounds,
+                            epoch=epoch, shared=len(group) > 1)
                 if (converged[req.slot]
                         or req.rounds >= self.cfg.max_rounds_per_query):
                     finished.append(self._finalize(req, new_alive, cand))
@@ -884,8 +887,7 @@ class GraphQueryService:
                              parent=req.span, rid=req.rid)
                 with obsv.activate(req.span), \
                         obsv.span("service.admit", slot=slot) as admit_span:
-                    with obsv.span("service.epoch_pin"):
-                        entry = self._pin_current()
+                    entry = self._pin_current()
                     req.epoch = entry.snapshot.epoch
                     admit_span.set_attrs(epoch=req.epoch)
                     self.active[slot] = req
@@ -899,10 +901,11 @@ class GraphQueryService:
                         # maintained store digests stand in for round one
                         from repro.core.incremental import store_prefilter
 
-                        alive_row = alive_row & store_prefilter(
-                            entry.snapshot.index, req.query,
-                            variant=self.cfg.filter_variant,
-                        )
+                        with obsv.span("service.prefilter"):
+                            alive_row = alive_row & store_prefilter(
+                                entry.snapshot.index, req.query,
+                                variant=self.cfg.filter_variant,
+                            )
                     if entry.snapshot.ooc is not None:
                         # fetch (or widen) this epoch's restricted edge set
                         # so it covers the new slot's seed.  Fail closed: a

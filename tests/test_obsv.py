@@ -123,6 +123,19 @@ class TestTracer:
             assert s is None
         obsv.end(None)  # no-op, no raise
 
+    def test_mirror_copies_exact_interval(self):
+        tr = obsv.Tracer()
+        root = tr.start_span("request", detached=True)
+        with tr.span("round") as live:
+            pass
+        copy = tr.mirror(live, parent=root, shared=True)
+        assert (copy.start_ns, copy.end_ns) == (live.start_ns, live.end_ns)
+        assert copy.name == "round" and copy.parent_id == root.span_id
+        assert copy.attrs == {"shared": True} and copy in tr.spans
+        assert obsv.mirror(live, parent=root) is None  # no tracer installed
+        with obsv.tracing():
+            assert obsv.mirror(obsv.NOOP_SPAN) is None
+
     def test_tracing_scope_installs_and_restores(self):
         assert obsv.get_tracer() is None
         with obsv.tracing() as tr:
@@ -134,6 +147,72 @@ class TestTracer:
             assert obsv.get_tracer() is tr  # nested scope restored us
         assert obsv.get_tracer() is None
         assert tr.names() == {"inside"}
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each annotation
+    entered, with its keywords, and each one left."""
+    import contextlib
+
+    import jax.profiler
+
+    log = []
+
+    @contextlib.contextmanager
+    def annotation(name, **kwargs):
+        log.append(("enter", name, kwargs))
+        try:
+            yield
+        finally:
+            log.append(("exit", name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+    return log
+
+
+class TestProfilerAnnotations:
+    def test_each_live_span_enters_one_bare_annotation(self, annotations):
+        with obsv.tracing() as tr:
+            with obsv.span("service.tick", active=2):
+                with obsv.span("service.admit", slot=0):
+                    pass
+        assert annotations == [
+            ("enter", "service.tick", {}), ("enter", "service.admit", {}),
+            ("exit", "service.admit"), ("exit", "service.tick"),
+        ]
+        assert tr.names() == {"service.tick", "service.admit"}
+
+    def test_annotation_left_when_the_span_raises(self, annotations):
+        with obsv.tracing() as tr:
+            with pytest.raises(RuntimeError):
+                with obsv.span("service.finalize"):
+                    raise RuntimeError("boom")
+        assert annotations == [("enter", "service.finalize", {}),
+                               ("exit", "service.finalize")]
+        assert [s.name for s in tr.spans] == ["service.finalize"]
+
+    def test_retroactive_detached_and_mirrored_spans_stay_out(
+            self, annotations):
+        import time
+
+        with obsv.tracing():
+            root = obsv.start_detached("service.request")
+            with obsv.activate(root):
+                obsv.span_at("service.queue_wait", time.perf_counter(),
+                             time.perf_counter())
+            with obsv.span("service.filter_round") as live:
+                pass
+            obsv.mirror(live, parent=root)
+            obsv.end(root)
+        assert annotations == [("enter", "service.filter_round", {}),
+                               ("exit", "service.filter_round")]
+
+    def test_no_tracer_builds_no_annotation(self, annotations):
+        assert obsv.span("service.tick", active=1) is obsv.NOOP_SPAN
+        with obsv.span("service.admit"):
+            pass
+        assert annotations == []
 
 
 # ---------------------------------------------------------------------------
@@ -234,6 +313,14 @@ class TestReports:
         with pytest.raises(ValueError, match="device_rounds"):
             obsv.EnumReport.from_dict(d)
         d = empty_enum_report()
+        d["host_syncs"] = 2.5
+        with pytest.raises(ValueError, match="host_syncs"):
+            obsv.EnumReport.from_dict(d)
+        d = empty_enum_report()
+        d.pop("host_syncs")
+        with pytest.raises(ValueError, match="missing.*host_syncs"):
+            obsv.EnumReport.from_dict(d)
+        d = empty_enum_report()
         d["scan_path"] = "gpu"
         with pytest.raises(ValueError, match="scan_path"):
             obsv.EnumReport.from_dict(d)
@@ -307,6 +394,11 @@ def _checked_query(data, q, *, max_embeddings=None, **engine_kwargs):
     assert isinstance(stats.extras["enum"], obsv.EnumReport)
     assert isinstance(stats.extras["plan"], obsv.PlanReport)
     assert stats.extras["enum"]["host_levels"] == 0
+    syncs = stats.extras["enum"]["host_syncs"]
+    assert type(syncs) is int and syncs >= 0
+    if stats.extras["enum"]["device_rounds"]:
+        # one validity bitmask a level at least, then the emit sync
+        assert syncs >= stats.extras["enum"]["device_rounds"] + 1
     return emb, stats, tr
 
 
@@ -433,7 +525,7 @@ def test_service_ooc_single_trace_and_metrics(tmp_path):
     in_trace = {s.name for s in tr.spans if s.trace_id == roots[0].trace_id}
     assert {
         "service.request", "service.queue_wait", "service.admit",
-        "service.epoch_pin", "service.filter_round", "service.finalize",
+        "service.filter_round", "service.finalize",
         "ooc.fetch", "ooc.manifest", "ooc.chunk",
         "query.plan", "query.enumerate", "enum.count", "enum.emit",
     } <= in_trace
@@ -480,3 +572,59 @@ def test_service_untraced_results_identical(tmp_path):
     with obsv.tracing():
         traced = run()
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(traced))
+
+
+def test_host_syncs_hand_count_on_the_host_scan_path():
+    """A 3-vertex path on the host-assisted scan: each of the 2 levels
+    syncs once for its one validity bitmask and once after emit (the
+    report asks for phase timings); then the table is read back."""
+    from repro.core.search import device_join_search
+
+    # data: 0-1-2-3 path with labels a b a b; query: a-b-a
+    data = build_graph(4, [0, 1, 0, 1], [(0, 1), (1, 2), (2, 3)])
+    q = build_graph(3, [0, 1, 0], [(0, 1), (1, 2)])
+    cand = (np.asarray(data.vlabels)[:, None]
+            == np.asarray(q.vlabels)[None, :])
+    report = {}
+    emb = device_join_search(data, q, cand, order=[0, 1, 2],
+                             use_kernel=False, report=report)
+    assert {tuple(r) for r in emb.tolist()} == {(0, 1, 2), (2, 1, 0)}
+    assert report["scan_path"] == "host" and report["device_rounds"] == 2
+    assert report["host_syncs"] == 2 * (1 + 1) + 1
+    obsv.EnumReport.from_dict(report)
+
+
+def test_service_filter_round_live_and_mirrored(tmp_path):
+    """Through the service: each fused dispatch is one live
+    ``service.filter_round`` under ``service.tick``, every request's copy
+    carries its exact interval (so deduplicating by interval counts each
+    dispatch once), and the store prefilter is a child of admission."""
+    from repro.core.incremental import IncrementalIndex
+    from repro.graphs.store import GraphStore
+
+    g = random_labeled_graph(150, 500, 4, seed=7)
+    store = GraphStore.from_graph(g)
+    store.attach_index(IncrementalIndex())
+    svc = GraphQueryService(store, GraphServiceConfig(enumerator="device"))
+    with obsv.tracing() as tr:
+        for i in range(2):
+            svc.submit(random_walk_query(g, 4, seed=8 + i))
+        done = svc.run_to_completion()
+    assert len(done) == 2 and not tr.open_spans
+    by_id = {s.span_id: s for s in tr.spans}
+    rounds = [s for s in tr.spans if s.name == "service.filter_round"]
+    live = [s for s in rounds if by_id[s.parent_id].name == "service.tick"]
+    copies = [s for s in rounds
+              if by_id[s.parent_id].name == "service.request"]
+    assert live and len(live) + len(copies) == len(rounds)
+    assert all({"epoch", "group"} <= set(s.attrs) for s in live)
+    intervals = {(s.start_ns, s.end_ns) for s in live}
+    assert len(intervals) == len(live)
+    assert {(s.start_ns, s.end_ns) for s in copies} == intervals
+    assert sum(s.attrs["group"] for s in live) == len(copies)
+    admits = [s for s in tr.spans if s.name == "service.admit"]
+    pre = [s for s in tr.spans if s.name == "service.prefilter"]
+    assert len(pre) == len(admits) == 2
+    assert {by_id[s.parent_id].name for s in pre} == {"service.admit"}
+    assert "service.epoch_pin" not in tr.names()
+    svc.shutdown()
