@@ -222,6 +222,82 @@ def cni_from_counts(counts: jnp.ndarray, d_max: int, max_p: int) -> CniValue:
     return CniValue(hi=hi.reshape(batch_shape), lo=lo.reshape(batch_shape))
 
 
+def _edge_positions(src: jnp.ndarray, n_vertices: int):
+    """One CNI position per directed edge record: (vertex (E,), rank (E,)).
+
+    The records of vertex v take the ranks 0 … deg_G(v)−1, so the pairs
+    cover every position a count row built from these edges can fill.
+    ``src`` need not be sorted: the pairs come from its sorted copy, which
+    also leaves ``vertex`` ascending.
+    """
+    vertex = jnp.sort(src.astype(jnp.int32))
+    deg_g = jnp.zeros(n_vertices, jnp.int32).at[vertex].add(
+        1, indices_are_sorted=True)
+    start = jnp.cumsum(deg_g) - deg_g
+    rank = jnp.arange(vertex.shape[0], dtype=jnp.int32) - start[vertex]
+    return vertex, rank
+
+
+def cni_from_edges(counts: jnp.ndarray, src: jnp.ndarray, d_max: int,
+                   max_p: int) -> CniValue:
+    """``cni_from_counts`` bit for bit, encoded over edge records.
+
+    counts: (..., V, L) int32, counted over the directed edge records whose
+    sources are ``src`` (E,), so no row holds more than its vertex's records
+    and ``_edge_positions`` covers every valid position.  The work is
+    O(B·E·L) for B = prod(leading dims) instead of the padded encode's
+    O(B·V·d_max) (DESIGN.md §3, "Encoding over edge records").
+    """
+    batch_shape = counts.shape[:-2]
+    n, L = counts.shape[-2:]
+    counts = counts.reshape((-1, n, L))
+    vertex, j = _edge_positions(src, n)
+    # row of descending cumulative counts at each position: block i (ord
+    # value L−i) holds the positions ccum[i-1] … ccum[i]−1
+    ccum = jnp.cumsum(counts[..., ::-1], axis=-1)[:, vertex, :]  # (B, E, L)
+    desc = jnp.diff(ccum, axis=-1, prepend=0)
+    done = ccum <= j[None, :, None]  # blocks that end at or before j
+    label = L - done.sum(-1)
+    block_start = jnp.where(done, desc, 0).sum(-1)
+    ords = jnp.arange(L, 0, -1, dtype=counts.dtype)
+    prefix = (jnp.where(done, desc * ords, 0).sum(-1)
+              + label * (j - block_start + 1))
+    valid = (j < ccum[..., -1]) & (j < d_max)
+
+    hi_t, lo_t = pascal_table_limbs(d_max, max_p)
+    q = jnp.minimum(j + 1, d_max)[None, :]
+    p = jnp.clip(prefix, 0, max_p)
+    terms = (jnp.where(valid, lo_t[q, p], 0).astype(jnp.uint32),
+             jnp.where(valid, hi_t[q, p], 0).astype(jnp.uint32))
+
+    # Every term is ≤ SAT64 = 2^62, so the sticky ``limb_add`` chain never
+    # wraps 2^64 and ends at min(Σ terms, SAT64): an exact sum in any order
+    # gives the same bits.  Sum ``bits``-wide chunks of the two limbs per
+    # vertex in uint32 (at most d_max terms each, so no chunk sum wraps),
+    # then propagate the carries.
+    if d_max >= 1 << 24:
+        raise ValueError(f"d_max {d_max} >= 2^24: chunk sums would wrap")
+    bits = 16 if d_max < 1 << 16 else 8
+    mask = jnp.uint32((1 << bits) - 1)
+    per_limb = 32 // bits
+    chunks = jnp.stack([(t >> (bits * k)) & mask
+                        for t in terms for k in range(per_limb)], axis=-1)
+    sums = jnp.zeros((counts.shape[0], n, 2 * per_limb), jnp.uint32)
+    sums = sums.at[:, vertex, :].add(chunks, indices_are_sorted=True)
+    carry = jnp.zeros(sums.shape[:-1], jnp.uint32)
+    limbs = [jnp.zeros_like(carry), jnp.zeros_like(carry)]  # lo, hi
+    for k in range(2 * per_limb):
+        t = sums[..., k] + carry
+        limbs[k // per_limb] |= (t & mask) << (bits * (k % per_limb))
+        carry = t >> bits
+    lo, hi = limbs
+    sat = (carry > 0) | (hi >= _SAT_HI)  # Σ ≥ 2^64, or Σ ≥ 2^62 = SAT64
+    hi = jnp.where(sat, _SAT_HI, hi)
+    lo = jnp.where(sat, _SAT_LO, lo)
+    return CniValue(hi=hi.reshape(batch_shape + (n,)),
+                    lo=lo.reshape(batch_shape + (n,)))
+
+
 def cni_log_from_counts(counts: jnp.ndarray, d_max: int, max_p: int) -> jnp.ndarray:
     """float32 log-space CNI (the TPU-kernel fast path): logsumexp of terms.
 

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import filters as flt
-from repro.core.cni import default_max_p
+from repro.core.cni import cni_from_edges, default_max_p
 from repro.core.labels import LabelMap, build_label_map, counts_matrix, ord_of
 from repro.graphs.csr import Graph, max_degree
 
@@ -59,7 +59,9 @@ def match_matrix(variant: str, counts: jnp.ndarray, ords: jnp.ndarray,
     Accepts an optional leading batch dim on every per-query array (counts
     (B, V, L), ords/alive (B, V), query digest fields (B, U)); ``q`` only
     needs ``counts`` / ``digest`` / ``mnd`` attributes, so the batched engine
-    passes its own stacked digest.
+    passes its own stacked digest.  ``counts`` counts ``g``'s edge records
+    (``counts_matrix*`` over ``g``): the ``cni`` variant encodes the data
+    side's CNI over those records (``cni.cni_from_edges``).
     """
     if variant == "nlf":
         return flt.nlf_match(counts, q.counts, ords, q.digest.ord_label)
@@ -74,14 +76,33 @@ def match_matrix(variant: str, counts: jnp.ndarray, ords: jnp.ndarray,
                                g.vlabels.shape[0], alive)
         gate = flt.mnd_match(mnd_d, q.mnd, ords, q.digest.ord_label)
         return gate & flt.nlf_match(counts, q.counts, ords, q.digest.ord_label)
-    digest = flt.make_digest(counts, ords, d_max, max_p)
     if variant == "cni":
+        # the data side's exact CNI over g's edge records (``counts`` counts
+        # them); the exact filter reads no log digest
+        digest = flt.VertexDigest(
+            ord_label=ords.astype(jnp.int32),
+            deg=counts.sum(-1).astype(jnp.int32),
+            cni=cni_from_edges(counts, g.src, d_max, max_p),
+            cni_log=None,
+        )
         return flt.cni_match(digest, q.digest)
+    digest = flt.make_digest(counts, ords, d_max, max_p)
     if variant == "cni_log":
         return flt.cni_match_log(digest, q.digest)
     raise ValueError(f"unknown filter variant: {variant}")
 
 
+def encoded_positions(variant: str, n_rows: int, g: Graph, d_max: int, *,
+                      padded: bool = False) -> int:
+    """CNI positions one ``match_matrix`` call encodes for ``n_rows`` count
+    rows per data vertex: one per directed edge record on the exact path,
+    ``d_max`` per vertex on the log path (and, with ``padded``, on the
+    per-shard ``distributed.local_match_matrix``), none for the others."""
+    if variant == "cni" and not padded:
+        return n_rows * g.n_directed_edges
+    if variant in ("cni", "cni_log"):
+        return n_rows * g.n_vertices * d_max
+    return 0
 
 
 @functools.partial(jax.jit, static_argnames=("d_max", "max_p", "variant",

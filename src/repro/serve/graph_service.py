@@ -71,6 +71,7 @@ from repro.core.batch_engine import (
 )
 from repro.core.cni import CniValue, default_max_p
 from repro.core.engine import QueryStats, search_filtered
+from repro.core.ilgf import encoded_positions
 from repro.graphs.csr import Graph, max_degree, to_host
 from repro.graphs.io import ChunkIOError
 from repro.graphs.store import BaseGraphStore, GraphSnapshot, as_snapshot
@@ -708,6 +709,11 @@ class GraphQueryService:
                         d_max=self.d_max, max_p=self.max_p,
                         variant=self.cfg.filter_variant,
                     )
+                round_span.set_attrs(encoded_positions=encoded_positions(
+                    self.cfg.filter_variant, self.cfg.max_slots,
+                    entry.snapshot.graph, self.d_max,
+                    padded=entry.sharded is not None,
+                ))
                 converged = ~np.asarray(changed)
             alive_merged = jnp.where(mask[:, None], new_alive, alive_merged)
             self._m_rounds.inc()

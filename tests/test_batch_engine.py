@@ -7,6 +7,9 @@ members of the same batch (all-pruned queries, filter-surviving queries with
 zero embeddings).  Also covers the slot-scheduled serving front-end.
 """
 
+import functools
+
+import jax
 import numpy as np
 import pytest
 
@@ -190,3 +193,142 @@ def test_graph_service_rejects_oversize():
     big = random_walk_query(g, 8, sparse=True, seed=2)
     with pytest.raises(ValueError):
         svc.submit(big)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _padded_match(g, ords, q, n_labels, d_max, max_p, alive):
+    """The padded encode's candidate grid: every vertex's CNI over d_max
+    positions (``make_digest`` → ``cni_from_counts``)."""
+    from repro.core import filters as flt
+    from repro.core.labels import counts_matrix_from_ords
+
+    counts = counts_matrix_from_ords(g, ords, n_labels, alive)
+    return flt.cni_match(flt.make_digest(counts, ords, d_max, max_p),
+                         q.digest)
+
+
+def _padded_fixed_point(g, ords, q, n_labels, d_max, max_p, alive):
+    """Peel with the padded encode until no row changes: (alive,
+    candidates, rounds), counted as the engines count them."""
+    rounds = 0
+    while True:
+        new = alive & _padded_match(g, ords, q, n_labels, d_max, max_p,
+                                    alive).any(-1)
+        rounds += 1
+        if not bool((new != alive).any()):
+            break
+        alive = new
+    match = _padded_match(g, ords, q, n_labels, d_max, max_p, alive)
+    return alive, match & alive[..., None], rounds
+
+
+def _power_law_slots():
+    """A seeded power-law graph whose hubs saturate, 6 queries stacked into
+    8 slots (2 inert), and a partial starting mask."""
+    from repro.core.batch_engine import stack_queries
+    from repro.core.cni import default_max_p
+    from repro.graphs import power_law_graph
+    from repro.graphs.csr import max_degree
+
+    g = power_law_graph(400, 6, 4, seed=5, gamma=2.1)
+    queries = [random_walk_query(g, 3 + i % 4, sparse=bool(i % 2),
+                                 seed=1400 + i) for i in range(6)]
+    d_max = max(1, max_degree(g))
+    l_pad = 4
+    max_p = default_max_p(d_max, l_pad)
+    qb = stack_queries(queries, g, d_max, max_p, 8, l_pad, 8)
+    rng = np.random.default_rng(14)
+    alive = (np.asarray(qb.ords) > 0) & (rng.random(qb.ords.shape) < 0.9)
+    return g, queries, qb, alive, l_pad, d_max, max_p
+
+
+@pytest.mark.parametrize("filt", ["round", "batched_fixed_point",
+                                  "sequential", "one_shot"])
+def test_filters_equal_padded_encode(filt):
+    """Every filter that runs the exact CNI over the edge records gives the
+    padded encode's results bit for bit on a graph whose hubs saturate."""
+    import jax.numpy as jnp
+
+    from repro.core.batch_engine import (
+        batched_ilgf_fixed_point, batched_ilgf_round,
+    )
+    from repro.core.cni import SAT64, default_max_p, limb_to_u64_np
+    from repro.core.filters import make_digest
+    from repro.core.ilgf import ilgf, one_shot_filter, prepare_query
+    from repro.core.labels import (
+        build_label_map, counts_matrix_from_ords, ord_of,
+    )
+
+    g, queries, qb, alive, l_pad, d_max, max_p = _power_law_slots()
+    counts = counts_matrix_from_ords(g, qb.ords, l_pad, jnp.asarray(alive))
+    cni = make_digest(counts, qb.ords, d_max, max_p).cni
+    assert (limb_to_u64_np(cni.hi, cni.lo) == SAT64).any()
+    kw = dict(n_labels=l_pad, d_max=d_max, max_p=max_p)
+    if filt == "round":
+        alive = jnp.asarray(alive)
+        for _ in range(3):
+            got = batched_ilgf_round(g, qb, alive, variant="cni", **kw)
+            want_alive = alive & _padded_match(
+                g, qb.ords, qb, l_pad, d_max, max_p, alive).any(-1)
+            want = (want_alive,
+                    _padded_match(g, qb.ords, qb, l_pad, d_max, max_p, alive)
+                    & want_alive[..., None],
+                    (want_alive != alive).any(-1))
+            for a, b in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            alive = got[0]
+        return
+    if filt == "batched_fixed_point":
+        got = batched_ilgf_fixed_point(g, qb, variant="cni", max_iters=1000,
+                                       **kw)
+        want = _padded_fixed_point(g, qb.ords, qb, l_pad, d_max, max_p,
+                                   qb.ords > 0)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        return
+    for q in queries[:3]:
+        n_labels = build_label_map(q).n_labels
+        mp = default_max_p(d_max, n_labels)
+        pq = prepare_query(q, d_max, mp)
+        ords = ord_of(pq.label_map, g.vlabels)
+        if filt == "sequential":
+            got = ilgf(g, q, variant="cni", d_max=d_max)
+            want = _padded_fixed_point(g, ords, pq, n_labels, d_max, mp,
+                                       ords > 0)
+        else:
+            got = one_shot_filter(g, q, variant="cni", d_max=d_max)
+            match = _padded_match(g, ords, pq, n_labels, d_max, mp, ords > 0)
+            cand = match.any(-1) & (ords > 0)
+            want = (cand, match & cand[:, None], 1)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_round_lowers_without_padded_expansion():
+    """At the service's widths (8 slots, d_max 1,024) the lowered exact
+    round holds no array of B·V·d_max elements, while the padded encode's
+    round does: the per-vertex d_max expansion cannot come back unnoticed."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.core.batch_engine import batched_ilgf_round
+    from repro.core.cni import default_max_p
+
+    g, _, qb, alive, l_pad, _, _ = _power_law_slots()
+    b, v, d_max = 8, g.n_vertices, 1024
+    max_p = default_max_p(d_max, l_pad)
+    kw = dict(n_labels=l_pad, d_max=d_max, max_p=max_p)
+    alive = jnp.asarray(alive)
+
+    def sizes(lowered):
+        return {int(np.prod([int(x) for x in m.group(1).split("x")]))
+                for m in re.finditer(r"tensor<(\d+(?:x\d+)*)x\w+>",
+                                     lowered.as_text())}
+
+    exact = sizes(batched_ilgf_round.lower(g, qb, alive, variant="cni", **kw))
+    padded = sizes(_padded_match.lower(g, qb.ords, qb, l_pad, d_max, max_p,
+                                       alive))
+    assert b * v * d_max in padded
+    table = (d_max + 1) * (max_p + 1)  # the Pascal limb tables
+    assert max(exact - {table}) < b * v * d_max, sorted(exact)[-4:]

@@ -14,6 +14,7 @@ from repro.core.cni import (
     _pascal_table_np,
     cni_exact_py,
     cni_from_counts,
+    cni_from_edges,
     cni_log_from_counts,
     default_max_p,
     limb_to_u64_np,
@@ -44,6 +45,11 @@ class TestPascalTable:
             row = t[q].astype(np.float64)
             assert (np.diff(row) >= 0).all()
         assert (t <= SAT64).all()
+
+    def test_terms_bounded_by_sat_at_service_widths(self):
+        # the edge-record encode's order-free sum rests on every term being
+        # at most SAT64 (DESIGN.md §3), at the widths the service runs
+        assert (_pascal_table_np(1024, 4096) <= SAT64).all()
 
 
 class TestBijection:
@@ -167,3 +173,79 @@ class TestSaturationSoundness:
         # The paper's arithmetic ("= 7") is internally inconsistent; we pin
         # our (correct) formula instead: labels {3, 1} descending = [3, 1].
         assert cni_exact_py([3, 1]) == math.comb(3, 1) + math.comb(5, 2)
+
+
+def _edge_case(name):
+    """(graph, ords (..., V), alive (..., V) or None, n_labels, d_max,
+    max_p) for one edge-record encode case."""
+    from repro.graphs import power_law_graph
+    from repro.graphs.csr import Graph, build_graph, max_degree
+
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "isolated":
+        # vertices 5..11 have no edge at all; 0 is a hub of degree 4
+        g = build_graph(12, np.zeros(12, np.int32),
+                        [(0, 1), (0, 2), (0, 3), (0, 4), (1, 2), (3, 4)])
+    elif name == "d_max_past_2_16":
+        # a star whose hub saturates, plus a ring; d_max ≥ 2^16 takes the
+        # 8-bit chunk sums
+        ring = [(i, i % 59 + 1) for i in range(1, 60)]
+        g = build_graph(60, np.zeros(60, np.int32),
+                        [(0, i) for i in range(1, 60)] + ring)
+        ords = rng.integers(1, 4, size=60).astype(np.int32)
+        return g, ords, None, 3, 1 << 16, 40
+    else:
+        g = power_law_graph(300, 8, 5, seed=1, gamma=2.1)
+    if name == "unsorted_src":
+        perm = rng.permutation(g.n_directed_edges)
+        g = Graph(vlabels=g.vlabels, src=g.src[perm], dst=g.dst[perm],
+                  elabels=g.elabels[perm])
+    n = g.n_vertices
+    d_max = max(1, max_degree(g))
+    if name == "batched_inert_partial":
+        ords = rng.integers(0, 6, size=(3, 2, n)).astype(np.int32)
+        ords[1, 0] = 0  # an inert slot
+        alive = rng.random((3, 2, n)) < 0.7
+        alive[2, 1] = True
+        return g, ords, alive, 8, d_max, default_max_p(d_max, 8)
+    ords = rng.integers(1, 6, size=n).astype(np.int32)
+    max_p = 16 if name == "max_p_clip" else default_max_p(d_max, 5)
+    return g, ords, None, 5, d_max, max_p
+
+
+@pytest.mark.parametrize("case", [
+    "power_law_hub", "max_p_clip", "batched_inert_partial", "isolated",
+    "unsorted_src", "d_max_past_2_16",
+])
+def test_edge_encode_equals_padded(case):
+    """The filter round's edge-record encode gives the padded encode's bits
+    (hi and lo) on every row, and the paper's exact value below saturation
+    wherever neither the max_p clip nor d_max cut the row."""
+    from repro.core.labels import counts_matrix_from_ords
+
+    g, ords, alive, n_labels, d_max, max_p = _edge_case(case)
+    counts = counts_matrix_from_ords(
+        g, jnp.asarray(ords), n_labels,
+        None if alive is None else jnp.asarray(alive))
+    want = cni_from_counts(counts, d_max, max_p)
+    got = cni_from_edges(counts, g.src, d_max, max_p)
+    np.testing.assert_array_equal(np.asarray(got.hi), np.asarray(want.hi))
+    np.testing.assert_array_equal(np.asarray(got.lo), np.asarray(want.lo))
+
+    rows = np.asarray(counts).reshape(-1, n_labels)
+    vals = limb_to_u64_np(got.hi, got.lo).reshape(-1)
+    weight = rows @ np.arange(1, n_labels + 1)  # the row's last prefix sum
+    exact = (vals < SAT64) & (weight <= max_p) & (rows.sum(1) <= d_max)
+    for row, val in zip(rows[exact], vals[exact]):
+        labels = [lab for lab, c in enumerate(row, start=1) for _ in range(c)]
+        assert int(val) == cni_exact_py(labels)
+    n_sat = int((vals == SAT64).sum())
+    n_clipped = int(((vals < SAT64) & (weight > max_p)).sum())
+    if case in ("power_law_hub", "unsorted_src", "d_max_past_2_16"):
+        assert n_sat > 0, "the hub rows must saturate"
+    if case == "max_p_clip":
+        assert n_clipped > 0, "some unsaturated prefix must pass max_p"
+    if case == "batched_inert_partial":
+        assert not vals.reshape(ords.shape)[1, 0].any()  # inert slot: 0
+    if case == "isolated":
+        assert not vals[5:].any() and exact.sum() == rows.shape[0]
