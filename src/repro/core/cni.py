@@ -319,6 +319,14 @@ def cni_log_from_counts(counts: jnp.ndarray, d_max: int, max_p: int) -> jnp.ndar
     return jnp.where(deg > 0, out, -jnp.inf).reshape(batch_shape)
 
 
+def _restart_exclusive_cumsum(x: np.ndarray, first: np.ndarray) -> np.ndarray:
+    """Exclusive cumulative sum of ``x ≥ 0`` that restarts wherever
+    ``first`` is set (the sums are non-decreasing, so the running max of
+    their values at the restarts is each segment's base)."""
+    before = np.cumsum(x) - x
+    return before - np.maximum.accumulate(np.where(first, before, 0))
+
+
 def cni_from_counts_np(counts: np.ndarray, d_max: int, max_p: int):
     """Host (numpy) twin of the device encode: (N, L) count rows ->
     (cni_u64 (N,), cni_log (N,) f32, deg (N,) int32).
@@ -327,61 +335,67 @@ def cni_from_counts_np(counts: np.ndarray, d_max: int, max_p: int):
     same ``min(p, max_p)`` clip, same sticky ``min(acc + term, SAT64)``
     saturating add — so host-maintained digests (batch assembly, the
     incremental store index) compare bit-identically against device digests.
-    Rows whose float64 term-sum shadow stays safely below SAT64 take a plain
-    uint64 sum (provably equal: partial sums are monotone, so no saturating
-    add can have fired); only near/over-saturation rows replay the sticky
+
+    The host twin of ``cni_from_edges``: it expands only the nonzero
+    (row, label) runs, each to its count of positions cut at ``d_max``, so
+    the work is O(N·L + Σ min(deg, d_max)), never the padded N·d_max.  Each
+    output row is a function of its own count row alone.  Rows whose
+    float64 term-sum shadow stays safely below SAT64 take a plain uint64 sum
+    (provably equal: partial sums are monotone, so no saturating add can
+    have fired); only near/over-saturation rows replay the sticky
     saturating accumulation.
     """
     counts = np.asarray(counts)
     n, L = counts.shape
-    deg_all = counts.sum(axis=1).astype(np.int32)
+    deg = counts.sum(axis=1).astype(np.int32)
+    cni_u64 = np.zeros(n, np.uint64)
+    cni_log = np.full(n, -np.inf, np.float32)
     if n == 0 or d_max <= 0:
-        return (
-            np.zeros(n, np.uint64),
-            np.full(n, -np.inf, np.float32),
-            deg_all,
-        )
+        return cni_u64, cni_log, deg
     table = _pascal_table_np(d_max, max_p)  # uint64, saturated at SAT64
     log_t = _log_hbar_np(d_max, max_p)
     sat = int(SAT64)
 
-    # vectorized descending expansion across all rows (the numpy twin of
-    # _descending_positions): label at position j = first ccum bin > j
+    # nonzero runs; reversing the columns makes np.nonzero's row-major
+    # order list each row's runs in descending ord (ord = L − column)
     desc = counts[:, ::-1]
-    ccum = np.cumsum(desc, axis=1)                              # (N, L)
-    posr = np.arange(d_max)
-    idx = (ccum[:, None, :] <= posr[None, :, None]).sum(-1)     # (N, D)
-    lab = np.maximum(L - idx, 0)
-    deg = ccum[:, -1]
-    valid = posr[None, :] < deg[:, None]
-    lab = np.where(valid, lab, 0)
-    prefix = np.minimum(np.cumsum(lab, axis=1), max_p)          # (N, D)
-    q_idx = np.arange(1, d_max + 1)
-    terms = np.where(valid, table[q_idx[None, :], prefix], 0)   # uint64
+    rows, cols = np.nonzero(desc)
+    runs = desc[rows, cols].astype(np.int64)
+    labels = L - cols
+    first = np.ones(rows.size, bool)
+    first[1:] = rows[1:] != rows[:-1]
+    j0 = _restart_exclusive_cumsum(runs, first)            # run's first rank
+    w0 = _restart_exclusive_cumsum(runs * labels, first)   # label sum before
+    take = np.clip(d_max - j0, 0, runs)                    # ranks < d_max
 
-    shadow_total = np.cumsum(terms.astype(np.float64), axis=1)[:, -1]
-    cni_u64 = terms.sum(axis=1, dtype=np.uint64)
-    for v in np.nonzero(shadow_total >= float(SAT64) * 0.5)[0]:
+    # one entry per encoded position, rows contiguous, ranks ascending
+    run = np.repeat(np.arange(runs.size), take)
+    if run.size == 0:
+        return cni_u64, cni_log, deg
+    k = np.arange(run.size) - np.repeat(np.cumsum(take) - take, take)
+    q = j0[run] + k + 1
+    prefix = np.minimum(w0[run] + labels[run] * (k + 1), max_p)
+    pos_rows = rows[run]
+    starts = np.flatnonzero(np.r_[True, pos_rows[1:] != pos_rows[:-1]])
+    ends = np.r_[starts[1:], run.size]
+    hit = pos_rows[starts]
+
+    terms = table[q, prefix]                                # uint64
+    cni_u64[hit] = np.add.reduceat(terms, starts)
+    shadow = np.add.reduceat(terms.astype(np.float64), starts)
+    for i in np.nonzero(shadow >= float(SAT64) * 0.5)[0]:
         # near/over saturation: replay the device's sticky saturating adds
         acc = 0
-        for j in range(1, min(int(deg[v]), d_max) + 1):
-            acc = min(acc + int(table[j, prefix[v, j - 1]]), sat)
-        cni_u64[v] = acc
+        for t in terms[starts[i]:ends[i]].tolist():
+            acc = min(acc + t, sat)
+        cni_u64[hit[i]] = acc
 
-    log_terms = np.where(valid, log_t[q_idx[None, :], prefix], -np.inf)
-    log_terms = log_terms.astype(np.float32)
-    m = log_terms.max(axis=1, initial=-np.inf)
-    m_safe = np.where(np.isfinite(m), m, np.float32(0.0))
-    s = np.sum(
-        np.where(valid, np.exp(log_terms - m_safe[:, None]), 0.0),
-        axis=1, dtype=np.float32,
-    )
-    cni_log = np.where(
-        deg > 0,
-        m_safe + np.log(np.maximum(s, np.float32(1e-30))),
-        -np.inf,
-    ).astype(np.float32)
-    return cni_u64, cni_log, deg_all
+    log_terms = log_t[q, prefix]                            # float32
+    m = np.maximum.reduceat(log_terms, starts)
+    e = np.exp(log_terms - np.repeat(m, ends - starts))
+    s = np.add.reduceat(e.astype(np.float64), starts).astype(np.float32)
+    cni_log[hit] = m + np.log(np.maximum(s, np.float32(1e-30)))
+    return cni_u64, cni_log, deg
 
 
 def cni_exact_py(labels: list[int]) -> int:

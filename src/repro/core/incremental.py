@@ -15,8 +15,9 @@ is too).  Applying an edge batch is a count-vector delta:
   vertex is untouched (its count row didn't change) — that is the whole
   point of the index.  Touched vertices re-encode their row with the same
   descending-ord, saturating-limb semantics as ``cni.py``
-  (``cni_from_counts_np``, device-bit-exact), O(|frontier| · d_max) instead
-  of O(V · d_max).
+  (``cni_from_counts_np``, device-bit-exact), over the nonzero count runs
+  only: O(|frontier| · L + Σ_frontier min(deg, d_max)) instead of a
+  re-encode of all V rows.
 
 * **Saturation semantics** (DESIGN.md §8):
   - insert-only touches of an already-*saturated* digest are **skipped
@@ -47,6 +48,7 @@ from typing import NamedTuple
 
 import numpy as np
 
+from repro import obsv
 from repro.core import filters as flt
 from repro.core.cni import (
     LOG_SAT64,
@@ -648,8 +650,10 @@ def store_digest(idx: IndexSnapshot, query_labels: np.ndarray,
     encode work); restricted alphabets re-encode from the gathered counts
     with the *index's* (d_max, max_p) so comparisons against a query digest
     encoded the same way stay device-bit-exact.  Returns (digest, counts_q,
-    ords_data) with numpy-backed fields.  ``ords`` may pass in the data-side
-    ord() values when the caller already computed them.
+    ords_data, encoded_positions) with numpy-backed fields, where
+    ``encoded_positions`` counts the positions the host encode produced (0
+    for the full universe).  ``ords`` may pass in the data-side ord() values
+    when the caller already computed them.
     """
     vlab = idx.vlabels
     if ords is None:
@@ -664,8 +668,10 @@ def store_digest(idx: IndexSnapshot, query_labels: np.ndarray,
     ):
         u64, log = idx.cni_u64, idx.cni_log
         deg = idx.deg
+        encoded = 0
     else:
         u64, log, deg = cni_from_counts_np(counts_q, idx.d_max, idx.max_p)
+        encoded = int(np.minimum(deg, idx.d_max).sum())
     digest = flt.VertexDigest(
         ord_label=ords,
         deg=deg,
@@ -675,11 +681,11 @@ def store_digest(idx: IndexSnapshot, query_labels: np.ndarray,
         ),
         cni_log=log,
     )
-    return digest, counts_q, ords
+    return digest, counts_q, ords, encoded
 
 
 def store_prefilter(idx: IndexSnapshot, query, *, variant: str = "cni",
-                    digest_cache: dict | None = None):
+                    digest_cache: dict | None = None, span=obsv.NOOP_SPAN):
     """One filtering pass from precomputed store digests: (V,) bool alive0.
 
     The store-backed replacement for the first ILGF round: no edge scatter,
@@ -689,8 +695,11 @@ def store_prefilter(idx: IndexSnapshot, query, *, variant: str = "cni",
     the counts matrix cannot provide — it falls back to the label filter.
 
     ``digest_cache``: optional dict the caller owns; the data-side digest
-    (the O(V·d_max) part for restricted alphabets) is memoized per query
+    (the encode over restricted count rows) is memoized per query
     alphabet, so a batch of same-alphabet queries encodes it once.
+
+    ``span``: the caller's span; it gets ``encoded_positions``, the
+    positions the data-side host encode produced (0 on a reused digest).
     """
     from repro.core.batch_engine import prepare_padded_query
 
@@ -703,10 +712,12 @@ def store_prefilter(idx: IndexSnapshot, query, *, variant: str = "cni",
     )
     key = query_labels.tobytes()
     cached = digest_cache.get(key) if digest_cache is not None else None
+    encoded = 0
     if cached is None:
-        cached = store_digest(idx, query_labels, ords=ords_data)
+        *cached, encoded = store_digest(idx, query_labels, ords=ords_data)
         if digest_cache is not None:
             digest_cache[key] = cached
+    span.set_attrs(encoded_positions=encoded)
     data_digest, counts_q, ords = cached
     if variant == "cni":
         match = flt.cni_match(data_digest, q_digest)

@@ -907,10 +907,11 @@ class GraphQueryService:
                         # maintained store digests stand in for round one
                         from repro.core.incremental import store_prefilter
 
-                        with obsv.span("service.prefilter"):
+                        with obsv.span("service.prefilter") as pre_span:
                             alive_row = alive_row & store_prefilter(
                                 entry.snapshot.index, req.query,
                                 variant=self.cfg.filter_variant,
+                                span=pre_span,
                             )
                     if entry.snapshot.ooc is not None:
                         # fetch (or widen) this epoch's restricted edge set

@@ -11,9 +11,11 @@ from hypothesis import strategies as st
 
 from repro.core.cni import (
     SAT64,
+    _log_hbar_np,
     _pascal_table_np,
     cni_exact_py,
     cni_from_counts,
+    cni_from_counts_np,
     cni_from_edges,
     cni_log_from_counts,
     default_max_p,
@@ -249,3 +251,121 @@ def test_edge_encode_equals_padded(case):
         assert not vals.reshape(ords.shape)[1, 0].any()  # inert slot: 0
     if case == "isolated":
         assert not vals[5:].any() and exact.sum() == rows.shape[0]
+
+
+def _padded_cni_np(counts, d_max, max_p):
+    """The padded host encode, kept as the oracle: a dense (N, d_max, L)
+    descending expansion, d_max positions per row."""
+    counts = np.asarray(counts)
+    n, L = counts.shape
+    deg_all = counts.sum(axis=1).astype(np.int32)
+    if n == 0 or d_max <= 0:
+        return np.zeros(n, np.uint64), np.full(n, -np.inf, np.float32), deg_all
+    table = _pascal_table_np(d_max, max_p)
+    log_t = _log_hbar_np(d_max, max_p)
+    ccum = np.cumsum(counts[:, ::-1], axis=1)
+    posr = np.arange(d_max)
+    idx = (ccum[:, None, :] <= posr[None, :, None]).sum(-1)
+    deg = ccum[:, -1]
+    valid = posr[None, :] < deg[:, None]
+    lab = np.where(valid, np.maximum(L - idx, 0), 0)
+    prefix = np.minimum(np.cumsum(lab, axis=1), max_p)
+    q_idx = np.arange(1, d_max + 1)
+    terms = np.where(valid, table[q_idx[None, :], prefix], 0)
+    shadow_total = np.cumsum(terms.astype(np.float64), axis=1)[:, -1]
+    cni_u64 = terms.sum(axis=1, dtype=np.uint64)
+    for v in np.nonzero(shadow_total >= float(SAT64) * 0.5)[0]:
+        acc = 0
+        for j in range(1, min(int(deg[v]), d_max) + 1):
+            acc = min(acc + int(table[j, prefix[v, j - 1]]), int(SAT64))
+        cni_u64[v] = acc
+    log_terms = np.where(valid, log_t[q_idx[None, :], prefix], -np.inf)
+    log_terms = log_terms.astype(np.float32)
+    m = log_terms.max(axis=1, initial=-np.inf)
+    m_safe = np.where(np.isfinite(m), m, np.float32(0.0))
+    s = np.sum(np.where(valid, np.exp(log_terms - m_safe[:, None]), 0.0),
+               axis=1, dtype=np.float32)
+    cni_log = np.where(deg > 0, m_safe + np.log(np.maximum(s, np.float32(1e-30))),
+                       -np.inf).astype(np.float32)
+    return cni_u64, cni_log, deg_all
+
+
+def _counts_case(name):
+    """(counts (N, L), d_max, max_p) for one host-encode case."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "empty":
+        return np.zeros((0, 7), np.int32), 8, 56
+    sparse = (rng.integers(0, 4, size=(40, 9))
+              * (rng.random((40, 9)) < 0.4)).astype(np.int32)
+    if name == "d_max_zero":
+        return sparse, 0, 16
+    if name == "all_zero_rows":
+        sparse[::3] = 0
+        return sparse, 8, default_max_p(8, 9)
+    if name == "deg_past_d_max":
+        return sparse * 3, 4, default_max_p(4, 9)
+    if name == "max_p_clip":
+        return sparse, 16, 12
+    if name == "saturation":
+        # one label per row, its count swept through the band where the
+        # row's sum passes SAT64/2 and SAT64; then dense hub rows past it
+        label = rng.integers(0, 307, size=600)
+        sweep = np.zeros((600, 307), np.int32)
+        sweep[np.arange(600), label] = rng.integers(1, 60, size=600)
+        hubs = rng.integers(0, 5, size=(40, 307)).astype(np.int32)
+        return np.concatenate([sweep, hubs]), 1024, default_max_p(1024, 307)
+    # a restricted alphabet of a seeded power-law graph, as admission
+    # gathers it from the store index's count matrix
+    from repro.graphs import power_law_graph
+
+    g = power_law_graph(2000, 8, 307, seed=3, gamma=2.1)
+    vl = np.asarray(g.vlabels)
+    full = np.zeros((g.n_vertices, 307), np.int32)
+    np.add.at(full, (np.asarray(g.src), vl[np.asarray(g.dst)]), 1)
+    alphabet = np.sort(rng.choice(307, size=12, replace=False))
+    d_max = 1 << int(np.ceil(np.log2(max(2, full.sum(1).max()))))
+    return full[:, alphabet], d_max, default_max_p(d_max, alphabet.size)
+
+
+_COUNTS_CASES = ["empty", "d_max_zero", "all_zero_rows", "deg_past_d_max",
+                 "max_p_clip", "saturation", "power_law_alphabet"]
+
+
+@pytest.mark.parametrize("case", _COUNTS_CASES)
+def test_host_encode_equals_padded(case):
+    """The host encode over nonzero count runs gives the padded encode's
+    ``cni_u64`` and ``deg`` bit for bit, and its ``cni_log`` to float32
+    rounding of the row sum."""
+    counts, d_max, max_p = _counts_case(case)
+    want = _padded_cni_np(counts, d_max, max_p)
+    got = cni_from_counts_np(counts, d_max, max_p)
+    assert got[0].dtype == np.uint64 and got[1].dtype == np.float32
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[2], want[2])
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-6, atol=0)
+    u64 = got[0]
+    if case == "saturation":
+        assert (u64 == SAT64).sum() > 0, "some rows must saturate"
+        band = (u64 >= SAT64 // np.uint64(2)) & (u64 < SAT64)
+        assert band.sum() > 0, "some rows must replay without saturating"
+    if case == "deg_past_d_max":
+        assert (want[2] > d_max).any()
+    if case == "max_p_clip":
+        weight = counts @ np.arange(1, counts.shape[1] + 1)  # ord = col + 1
+        assert (weight > max_p).any()
+    if case == "all_zero_rows":
+        assert not u64[::3].any() and np.isneginf(got[1][::3]).all()
+    if case == "power_law_alphabet":
+        assert (want[2] > 0).sum() > 50
+
+
+@pytest.mark.parametrize("case", ["saturation", "power_law_alphabet"])
+def test_host_encode_row_local(case):
+    """Encoding a random subset of rows gives those rows of the full
+    encode, bitwise in all three outputs."""
+    counts, d_max, max_p = _counts_case(case)
+    full = cni_from_counts_np(counts, d_max, max_p)
+    rows = np.random.default_rng(5).permutation(counts.shape[0])[:97]
+    sub = cni_from_counts_np(counts[rows], d_max, max_p)
+    for got, want in zip(sub, full):
+        np.testing.assert_array_equal(got, want[rows])

@@ -628,3 +628,29 @@ def test_service_filter_round_live_and_mirrored(tmp_path):
     assert {by_id[s.parent_id].name for s in pre} == {"service.admit"}
     assert "service.epoch_pin" not in tr.names()
     svc.shutdown()
+
+
+def test_service_prefilter_counts_encoded_positions():
+    """A served query on an indexed store records on its
+    ``service.prefilter`` span the positions the host digest encode
+    produced: its restricted count rows' nonzero positions, capped at
+    d_max per row."""
+    from repro.core.incremental import IncrementalIndex, gathered_counts
+    from repro.graphs.store import GraphStore
+
+    g = random_labeled_graph(150, 500, 6, seed=11)
+    store = GraphStore.from_graph(g)
+    store.attach_index(IncrementalIndex())
+    snap = store.index.freeze()
+    q = random_walk_query(g, 3, seed=4)
+    alphabet = np.unique(np.asarray(q.vlabels))
+    assert alphabet.size < snap.universe.size  # a restricted alphabet
+    counts = gathered_counts(snap, alphabet)
+    want = int(np.minimum(counts.sum(axis=1), snap.d_max).sum())
+    svc = GraphQueryService(store, GraphServiceConfig(enumerator="device"))
+    with obsv.tracing() as tr:
+        svc.submit(q)
+        assert len(svc.run_to_completion()) == 1
+    (pre,) = [s for s in tr.spans if s.name == "service.prefilter"]
+    assert want > 0 and pre.attrs["encoded_positions"] == want
+    svc.shutdown()
